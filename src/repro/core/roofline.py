@@ -69,6 +69,30 @@ H100_LIKE = HardwareSpec("h100_like", peak_flops=989e12 / 66,
                          ici_bw=450e9, ici_links=1, num_units=66,
                          alpha=3e-6, bw_gamma=0.32)
 
+# Serving hardware by ``device_kind`` as JAX reports it. TPU v5e reports
+# itself as "TPU v5 lite" (peaks: Google Cloud documentation, "TPU v5e").
+# The CPU backend runs tests and prices the v5e virtual target, so the
+# predicted clock there reads as v5e time. Any other kind is an error: a
+# device the table does not know would be priced with another chip's peaks.
+DEVICE_KIND_SPECS = {
+    "TPU v5 lite": TPU_V5E,
+    "cpu": TPU_V5E,
+}
+
+
+def hardware_spec(device_kind: str) -> HardwareSpec:
+    """The :class:`HardwareSpec` to price a device of ``device_kind``.
+
+    Raises:
+        ValueError: the kind is not in :data:`DEVICE_KIND_SPECS`.
+    """
+    try:
+        return DEVICE_KIND_SPECS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware spec for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_KIND_SPECS)}") from None
+
 
 @dataclass(frozen=True)
 class RequestLoad:

@@ -1,5 +1,5 @@
 """Pallas TPU kernels for the serving hot spots, each with a pure-jnp oracle
-in ref.py and a jit wrapper in ops.py (interpret=True off-TPU):
+in ref.py and a jit wrapper in ops.py (interpret=True on the CPU backend):
 
   flash_prefill        — causal GQA flash attention (chunk-offset aware)
   paged_decode         — decode attention over paged KV (block tables via

@@ -57,7 +57,7 @@ def _attend_tile(tile_live, q, k, v, k_pos, pos_ref, m_ref, l_ref, acc_ref,
         preferred_element_type=jnp.float32).reshape(G, bq, rep, -1)
     s = s * sm_scale
 
-    pos = pos_ref[...][:, 0]              # (bq,)
+    pos = pos_ref[0][:, 0]                # (bq,)
     row_pos = pos[None, :, None, None]
     valid = (k_pos <= row_pos) & (row_pos >= 0) & tile_live
     s = jnp.where(valid, s, NEG_INF)
@@ -73,6 +73,13 @@ def _attend_tile(tile_live, q, k, v, k_pos, pos_ref, m_ref, l_ref, acc_ref,
         (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32).reshape(G, bq, rep, Dh)
     acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
+
+
+def _tile_rows(row_pos, num_tiles: int, block_q: int):
+    """(T*block_q, 1) row positions -> (T, block_q, 1), so one tile's
+    block spans the array's last two dims: Mosaic takes a (block_q, 1)
+    block of the flat layout only when block_q is a multiple of 8."""
+    return row_pos.astype(jnp.int32).reshape(num_tiles, block_q, 1)
 
 
 def _write_tile(o_ref, m_ref, l_ref, acc_ref):
@@ -137,7 +144,7 @@ def duet_attention(q, row_pos, tile_slot, k_slab, v_slab, *,
             grid=(T, S // block_k),
             in_specs=[
                 pl.BlockSpec((block_q, H, Dh), lambda t, j, ts: (t, 0, 0)),
-                pl.BlockSpec((block_q, 1), lambda t, j, ts: (t, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda t, j, ts: (t, 0, 0)),
                 pl.BlockSpec((1, block_k, G, Dh),
                              lambda t, j, ts: (jnp.maximum(ts[t], 0), j, 0, 0)),
                 pl.BlockSpec((1, block_k, G, Dh),
@@ -153,8 +160,8 @@ def duet_attention(q, row_pos, tile_slot, k_slab, v_slab, *,
         ),
         out_shape=jax.ShapeDtypeStruct((R, H, Dh), q.dtype),
         interpret=interpret,
-    )(tile_slot.astype(jnp.int32), q, row_pos.astype(jnp.int32), k_slab,
-      v_slab)
+    )(tile_slot.astype(jnp.int32), q, _tile_rows(row_pos, T, block_q),
+      k_slab, v_slab)
     return out
 
 
@@ -221,7 +228,8 @@ def duet_attention_paged(q, row_pos, tile_slot, k_pages, v_pages, tables, *,
             in_specs=[
                 pl.BlockSpec((block_q, H, Dh),
                              lambda t, j, ts, tbl: (t, 0, 0)),
-                pl.BlockSpec((block_q, 1), lambda t, j, ts, tbl: (t, 0)),
+                pl.BlockSpec((1, block_q, 1),
+                             lambda t, j, ts, tbl: (t, 0, 0)),
                 pl.BlockSpec((1, ps, G, Dh),
                              lambda t, j, ts, tbl:
                              (tbl[jnp.maximum(ts[t], 0), j], 0, 0, 0)),
@@ -240,5 +248,5 @@ def duet_attention_paged(q, row_pos, tile_slot, k_pages, v_pages, tables, *,
         out_shape=jax.ShapeDtypeStruct((R, H, Dh), q.dtype),
         interpret=interpret,
     )(tile_slot.astype(jnp.int32), tables.astype(jnp.int32), q,
-      row_pos.astype(jnp.int32), k_pages, v_pages)
+      _tile_rows(row_pos, T, block_q), k_pages, v_pages)
     return out

@@ -1,7 +1,7 @@
 """jit'd public wrappers for the Pallas kernels + the duet schedule builder.
 
-``interpret`` defaults to True off-TPU so the kernels validate on CPU
-(the assignment's kernel-validation mode); on a TPU backend they compile to
+``interpret`` defaults to True on the CPU backend only, so the kernels
+validate there (the kernel-validation mode); on a TPU backend they compile to
 Mosaic.
 
 This module is also the single source of truth for the kernel-wide
@@ -82,15 +82,17 @@ from repro.kernels import flash_prefill as _flash  # noqa: E402
 from repro.kernels import paged_decode as _paged  # noqa: E402
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def default_interpret() -> bool:
+    """Pallas interpret mode: on where the backend is the CPU (tests),
+    off everywhere else — on the chip the kernels always compile to Mosaic."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("q_offset", "kv_len", "block_q",
                                              "block_k", "interpret"))
 def flash_prefill(q, k, v, *, q_offset: int = 0, kv_len=None,
                   block_q: int = 128, block_k: int = 128, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     return _flash.flash_prefill(q, k, v, q_offset=q_offset, kv_len=kv_len,
                                 block_q=block_q, block_k=block_k,
                                 interpret=interpret)
@@ -98,7 +100,7 @@ def flash_prefill(q, k, v, *, q_offset: int = 0, kv_len=None,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode(q, k_pages, v_pages, tables, lengths, *, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     return _paged.paged_decode(q, k_pages, v_pages, tables, lengths,
                                interpret=interpret)
 
@@ -106,7 +108,7 @@ def paged_decode(q, k_pages, v_pages, tables, lengths, *, interpret=None):
 @functools.partial(jax.jit, static_argnames=("num_splits", "interpret"))
 def paged_decode_splitkv(q, k_pages, v_pages, tables, lengths, *,
                          num_splits: int, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     return _paged.paged_decode_splitkv(q, k_pages, v_pages, tables, lengths,
                                        num_splits=num_splits,
                                        interpret=interpret)
@@ -120,9 +122,8 @@ def paged_decode_sharded(q, k_pages, v_pages, tables, lengths, *, mesh,
     page pools (N, ps, G/tp, Dh); block tables and lengths stay host-global
     (replicated) — page ids index the page axis, which is NOT partitioned.
     Softmax is per-head and heads are fully partitioned, so no cross-shard
-    reduction is needed and ``check_rep=False`` is sound.
+    reduction is needed and ``check_vma=False`` is sound.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(qs, kp, vp, tbl, ln):
@@ -134,9 +135,9 @@ def paged_decode_sharded(q, k_pages, v_pages, tables, lengths, *, mesh,
 
     head_spec = P(None, "model", None)
     pool_spec = P(None, None, "model", None)
-    return shard_map(local, mesh=mesh,
-                     in_specs=(head_spec, pool_spec, pool_spec, P(), P()),
-                     out_specs=head_spec, check_rep=False)(
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(head_spec, pool_spec, pool_spec, P(), P()),
+                         out_specs=head_spec, check_vma=False)(
         q, k_pages, v_pages, tables, lengths)
 
 
@@ -150,7 +151,7 @@ def paged_decode_auto(q, k_pages, v_pages, tables, lengths, *, mesh=None,
     and to the split-KV kernel when the table capacity crosses the
     (roofline-priced) threshold.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     splits = num_splits_for(tables.shape[1], k_pages.shape[1],
                             split_threshold)
     if mesh is not None and mesh.shape.get("model", 1) > 1:
@@ -169,7 +170,7 @@ def paged_decode_auto(q, k_pages, v_pages, tables, lengths, *, mesh=None,
                                              "interpret"))
 def duet_attention(q, row_pos, tile_slot, k_slab, v_slab, *,
                    block_q: int = 8, block_k: int = 128, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     return _duet.duet_attention(q, row_pos, tile_slot, k_slab, v_slab,
                                 block_q=block_q, block_k=block_k,
                                 interpret=interpret)
@@ -178,7 +179,7 @@ def duet_attention(q, row_pos, tile_slot, k_slab, v_slab, *,
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
 def duet_attention_paged(q, row_pos, tile_slot, k_pages, v_pages, tables, *,
                          block_q: int = 8, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     return _duet.duet_attention_paged(q, row_pos, tile_slot, k_pages,
                                       v_pages, tables, block_q=block_q,
                                       interpret=interpret)

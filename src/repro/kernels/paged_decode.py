@@ -156,9 +156,10 @@ def _splitkv_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
     # a split whose pages lie entirely past `lengths` has every score at
     # NEG_INF; exp(NEG_INF - NEG_INF) == 1 would silently inflate l, so
-    # the probabilities are forced to zero until the split sees a token
-    live = m_new > MASKED_M_THRESHOLD
-    p = jnp.where(live[..., None], jnp.exp(s - m_new[..., None]), 0.0)
+    # masked scores get zero probability. The full-shape `valid` mask does
+    # this without broadcasting a (G, rep) boolean, which Mosaic cannot
+    # reshape.
+    p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[si] = alpha * l_prev + jnp.sum(p, axis=-1)
     m_ref[si] = m_new

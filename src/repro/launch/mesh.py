@@ -22,13 +22,22 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules here
+    are annotations the compiler propagates, not explicit-sharding types
+    (``jax.make_mesh`` defaults to ``Explicit``, under which the sharded
+    embedding gather raises ``ShardingTypeError``)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2,
@@ -52,8 +61,8 @@ def make_test_mesh(data: int = 2, model: int = 2,
             "XLA_FLAGS=--xla_force_host_platform_device_count=N (before "
             "importing jax) or reduce the requested parallelism")
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:

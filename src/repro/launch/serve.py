@@ -1,10 +1,19 @@
 """Serving launcher: run the DuetServe engine on a trace (CLI).
 
-On a real TPU slice this process drives one replica; the duet decision is
-taken per iteration (core.multiplexer) and realised either at kernel-grid
-granularity (single chip — kernels.duet_attention) or by splitting the model
-axis into sub-meshes (``mesh.split_duet_submeshes``). On CPU the engine runs
-reduced configs end-to-end with the virtual TPU clock (serving/engine.py).
+On a TPU this process drives one replica (or ``--dp`` replicas, one per
+chip); the duet decision is taken per iteration (core.multiplexer) and
+realised either at kernel-grid granularity (single chip —
+kernels.duet_attention) or by splitting the model axis into sub-meshes
+(``mesh.split_duet_submeshes``). On the CPU the same path runs reduced
+configs for tests. Parameters are initialised from ``--seed`` in bf16, and
+the engine keeps its KV pools in the same dtype.
+
+Timing: the summary's TTFT/TBT/throughput figures come from the engine's
+virtual clock, which advances by the roofline's prediction for the device
+(serving/engine.py) — they are predictions on every platform, the chip
+included. ``wall_setup_s`` / ``wall_serve_s`` are the host clock: parameter
+init plus engine construction, then serving (jit compiles included) up to
+the last device output.
 
 Two execution modes:
 
@@ -36,12 +45,15 @@ import argparse
 import dataclasses
 import json
 import sys
+import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, list_configs, reduced
 from repro.core.device import DeviceContext
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import Model
 from repro.serving.async_engine import (AsyncDuetEngine, FinishEvent,
                                         TokenEvent)
@@ -87,10 +99,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--attn-kernel", action="store_true",
                     help="route decode attention through the Pallas kernels; "
                          "the engine probes the geometry and reports the "
-                         "resolved path as kernel_path (pallas, "
-                         "pallas_sharded under --tp > 1, or jnp fallback). "
-                         "With --no-clamp an unusable kernel request is an "
-                         "error instead of a warn-and-fallback")
+                         "resolved path as kernel_path (pallas, or "
+                         "pallas_sharded under --tp > 1); a geometry the "
+                         "kernels cannot serve is an error")
     ap.add_argument("--split-kv-threshold", type=int, default=None,
                     help="block-table capacity (tokens) above which the "
                          "kernel path decodes with the flash-decoding "
@@ -253,14 +264,24 @@ def _clamp_lengths(reqs, max_len: int, clamp: bool):
     return reqs
 
 
-def main(argv=None):
+def serve(argv=None):
+    """Parse ``argv``, serve the synthesised trace, stream events to stdout.
+
+    Returns:
+        ``(summary, requests, server)``: the summary dict :func:`main`
+        prints; the served :class:`~repro.serving.request.Request` objects
+        (their ``output_tokens`` hold the generated tokens); and the engine
+        that served them (the :class:`Router` under ``--dp``/``--elastic``),
+        which still holds its parameters and KV pools on the devices.
+    """
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    t_start = time.perf_counter()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
     model = Model(cfg, attn_kernel=args.attn_kernel)
-    params = model.init(jax.random.PRNGKey(args.seed))
 
     # elastic replica set: the mesh holds max-dp replicas; dispatch starts
     # at min-dp and breathes against measured outstanding tokens
@@ -284,6 +305,13 @@ def main(argv=None):
     ctx = None
     if args.tp > 1 or dp > 1:
         ctx = DeviceContext.for_shape(cfg, tp=args.tp, dp=dp)
+    # parameters materialise on the first replica's first device; each
+    # engine then places its own copy (other replicas copy device to
+    # device; TP shards), so no chip ends up holding two copies
+    home = (jax.devices()[0] if ctx is None
+            else ctx.split_replicas()[0].mesh.devices.flat[0])
+    with jax.default_device(home):
+        params = model.init(jax.random.PRNGKey(args.seed), jnp.bfloat16)
 
     # trace synthesis: the seeded open-loop generator when any stochastic
     # load knob is non-default, the legacy synth_trace otherwise
@@ -331,7 +359,6 @@ def main(argv=None):
         kv_quant=args.kv_quant,
         temperature=args.temperature,
         split_kv_threshold=args.split_kv_threshold,
-        strict_kernel=args.attn_kernel and not args.clamp,
         tp=args.tp, units=max(1, args.tp))
     if args.split_kv_threshold is not None and not args.attn_kernel:
         _warn("--split-kv-threshold only applies with --attn-kernel; "
@@ -370,7 +397,9 @@ def main(argv=None):
                         else DuetEngine,
                         seed=args.seed, elastic=elastic_cfg)
         router.submit(reqs)
+        t_setup = _wall(t_start, [e.params for e in router.engines])
         router.run(on_event=print_event if args.stream else None)
+        t_serve = _wall(t_start + t_setup, [e.pools for e in router.engines])
         if args.stream:
             print(json.dumps({
                 "event": "mesh", **router.ctx.describe(),
@@ -395,12 +424,13 @@ def main(argv=None):
             pc = router.prefix_stats()
             pc.pop("per_replica", None)
             out["prefix_cache"] = pc
-        print(json.dumps(out, indent=2))
-        return
+        out["wall_setup_s"], out["wall_serve_s"] = t_setup, t_serve
+        return out, reqs, router
 
     if args.stream:
         engine = AsyncDuetEngine(model, params, ec, seed=args.seed,
                                  ctx=ctx)
+        t_setup = _wall(t_start, engine.params)
         engine.submit(reqs)   # open-loop: arrivals replay on the inbox
         for ev in engine.events():
             print_event(ev)
@@ -421,6 +451,7 @@ def main(argv=None):
         out["dispatch_stats"] = dataclasses.asdict(engine.dstats)
     else:
         engine = DuetEngine(model, params, ec, seed=args.seed, ctx=ctx)
+        t_setup = _wall(t_start, engine.params)
         engine.submit(reqs)
         metrics = engine.run()
         out = metrics.summary()
@@ -433,7 +464,23 @@ def main(argv=None):
         engine.ctx.collectives_per_iteration()
     if args.paged:
         out["prefix_cache"] = engine.kv_mgr.prefix_stats()
+    out["wall_setup_s"] = t_setup
+    out["wall_serve_s"] = _wall(t_start + t_setup,
+                                (engine.pools, engine.cache))
+    return out, reqs, engine
+
+
+def _wall(since: float, outputs) -> float:
+    """Host-clock seconds from ``since`` until ``outputs`` are computed."""
+    jax.block_until_ready(outputs)
+    return time.perf_counter() - since
+
+
+def main(argv=None) -> dict:
+    """CLI entry point: serve, print the JSON summary, and return it."""
+    out, _, _ = serve(argv)
     print(json.dumps(out, indent=2))
+    return out
 
 
 if __name__ == "__main__":

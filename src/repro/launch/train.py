@@ -17,7 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, list_configs, reduced
 from repro.data import data_iterator
-from repro.launch.mesh import data_axes
+from repro.launch.mesh import data_axes, make_mesh
 from repro.models.params import param_shardings, tp_adjusted_config
 from repro.models.transformer import Model
 from repro.training.optimizer import AdamWConfig, init_adamw
@@ -41,7 +41,7 @@ def main(argv=None):
 
     n_dev = len(jax.devices())
     dp = args.data or max(1, n_dev // args.model)
-    mesh = jax.make_mesh((dp, args.model), ("data", "model"))
+    mesh = make_mesh((dp, args.model), ("data", "model"))
 
     cfg = get_config(args.arch)
     if args.reduced:

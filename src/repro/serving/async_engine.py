@@ -50,7 +50,7 @@ import numpy as np
 from repro.configs.base import GQA_KINDS
 from repro.core.device import DeviceContext
 from repro.core.lookahead import make_superiter_fn
-from repro.core.roofline import HardwareSpec, TPU_V5E
+from repro.core.roofline import HardwareSpec
 from repro.kernels import build_duet_schedule
 from repro.models.transformer import Model
 from repro.serving.engine import DuetEngine, EngineConfig
@@ -136,7 +136,7 @@ class AsyncDuetEngine(DuetEngine):
     """
 
     def __init__(self, model: Model, params, engine_cfg: EngineConfig,
-                 hw: HardwareSpec = TPU_V5E, seed: int = 0,
+                 hw: Optional[HardwareSpec] = None, seed: int = 0,
                  ctx: Optional[DeviceContext] = None):
         super().__init__(model, params, engine_cfg, hw=hw, seed=seed,
                          ctx=ctx)

@@ -8,8 +8,12 @@ provides — host CPU devices in tests/CI, TPU chips on the serving target.
 The engine clock deliberately advances by the attention-aware roofline
 prediction rather than wall time — the same oracle the paper's scheduler
 uses and validates (Fig. 8; reproduced against real JAX wall-time in
-benchmarks/fig8) — so metrics (TTFT/TBT/throughput) are TPU-v5e-scale and
-reproducible across hosts while every generated token is real.
+benchmarks/fig8). So its metrics (TTFT/TBT/throughput) are predictions for
+the device the replica runs on (``roofline.hardware_spec``; the CPU prices
+the v5e virtual target) on every platform, the chip included, and they are
+reproducible across hosts while every generated token is real. Measured
+time comes only from the host clock around the run (``serve.py``'s
+``wall_*`` fields).
 
 KV memory (DESIGN.md §3): by default attention KV lives in per-layer device
 page pools (PagedAttention layout) addressed through per-request block
@@ -47,7 +51,7 @@ from repro.configs.base import GQA_KINDS, ArchConfig
 from repro.core.device import DeviceContext
 from repro.core.lookahead import make_lookahead_fn, make_paged_lookahead_fn
 from repro.core.multiplexer import AdaptiveMultiplexer
-from repro.core.roofline import HardwareSpec, RooflineModel, TPU_V5E
+from repro.core.roofline import HardwareSpec, RooflineModel, hardware_spec
 from repro.models.transformer import Model
 from repro.serving.kvcache import (DEFAULT_PAGE_SIZE, HostPoolConfig,
                                    PagedKVCacheManager, PagePoolConfig,
@@ -97,25 +101,29 @@ class EngineConfig:
     kv_quant: str = "none"
     # Pallas kernel path (Model.attn_kernel engines). The capability probe
     # resolves the executed path into ``DuetEngine.kernel_path`` (one of
-    # KERNEL_PATHS); ``strict_kernel`` turns an unusable kernel request
-    # into an error instead of a warn-and-fallback (--no-clamp semantics).
-    # ``split_kv_threshold``: table capacity in tokens above which decode
-    # uses the flash-decoding split-KV kernel — None prices the threshold
-    # from the roofline, 0 disables splitting.
+    # KERNEL_PATHS); a kernel request the geometry cannot serve is a
+    # ValueError. ``split_kv_threshold``: table capacity in tokens above
+    # which decode uses the flash-decoding split-KV kernel — None prices
+    # the threshold from the roofline, 0 disables splitting.
     split_kv_threshold: Optional[int] = None
-    strict_kernel: bool = False
 
     KERNEL_PATHS = ("jnp", "pallas", "pallas_sharded")
 
 
+def params_dtype(params) -> jnp.dtype:
+    """Storage dtype of a parameter tree (its embedding table's): the
+    engine keeps its page pools and state cache in the same dtype."""
+    emb = params.get("embedding", params.get("codebook_embeddings"))
+    return emb.dtype
+
+
 class DuetEngine:
     def __init__(self, model: Model, params, engine_cfg: EngineConfig,
-                 hw: HardwareSpec = TPU_V5E, seed: int = 0,
+                 hw: Optional[HardwareSpec] = None, seed: int = 0,
                  ctx: Optional[DeviceContext] = None):
         self.model = model
         self.cfg: ArchConfig = model.cfg
         self.ec = engine_cfg
-        self.hw = hw
         self.key = jax.random.PRNGKey(seed)
         self.paged = engine_cfg.paged
 
@@ -123,6 +131,9 @@ class DuetEngine:
         # degenerate 1-device mesh, so there is exactly one execution path
         # and TP>1 cannot drift from the tested single-chip behavior.
         self.ctx = ctx if ctx is not None else DeviceContext.single(self.cfg)
+        # the roofline prices the device this replica runs on
+        self.hw = hw if hw is not None else hardware_spec(
+            self.ctx.mesh.devices.flat[0].device_kind)
         if engine_cfg.tp not in (1, self.ctx.tp) and self.ctx.tp != 1:
             raise ValueError(
                 f"EngineConfig.tp={engine_cfg.tp} contradicts the device "
@@ -134,7 +145,7 @@ class DuetEngine:
         # actually execute and pin it on a per-engine Model copy (other
         # engines may share the Model). ``kernel_path`` is the explicit
         # report — surfaced by serve.py in summaries and the JSONL mesh
-        # event — replacing the old blanket warn-and-fallback.
+        # event; a kernel request the geometry cannot serve is an error.
         model = copy.copy(model)
         self.kernel_path = "jnp"
         if model.attn_kernel:
@@ -151,22 +162,15 @@ class DuetEngine:
                     if not self.paged else
                     f"kv heads ({self.cfg.num_kv_heads}) do not shard over "
                     f"the model axis ({self.ctx.tp})")
-                msg = (f"attn_kernel unusable under this geometry ({reason});"
-                       " falling back to the sharded jnp attention path")
-                if engine_cfg.strict_kernel:
-                    raise ValueError(msg)
-                warnings.warn(msg)
-                model.attn_kernel = False
-            if self.kernel_path != "jnp" and self.paged:
+                raise ValueError(
+                    f"attn_kernel unusable under this geometry ({reason})")
+            if self.paged:
                 thr = engine_cfg.split_kv_threshold
                 if thr is None:  # roofline-priced default; 0 disables
                     thr = RooflineModel(
-                        self.cfg, hw, tp=self._tp,
+                        self.cfg, self.hw, tp=self._tp,
                         page_size=engine_cfg.page_size).split_kv_threshold()
                 model.split_kv_threshold = int(thr)
-        elif engine_cfg.strict_kernel:
-            raise ValueError(
-                "strict_kernel requires a Model built with attn_kernel=True")
         assert self.kernel_path in EngineConfig.KERNEL_PATHS
         self.model = model
         self.params = self.ctx.place_params(params)
@@ -186,6 +190,9 @@ class DuetEngine:
             self.prefix_cache = False
 
         ps = engine_cfg.page_size
+        # pools and state cache store KV in the parameters' dtype (bf16
+        # serving keeps bf16 KV; float32 oracles stay float32 end to end)
+        dtype = params_dtype(self.params)
         if self.paged:
             pool_tokens = engine_cfg.kv_pool_tokens \
                 or engine_cfg.max_slots * engine_cfg.max_len
@@ -200,10 +207,10 @@ class DuetEngine:
                 prefix_cache=self.prefix_cache, host_pool=host_pool)
             # block-table width: one request may span the whole pool
             self.max_pages = num_pages - 1
-            self.pools = init_page_pools(self.cfg, self.kv_mgr.pool,
+            self.pools = init_page_pools(self.cfg, self.kv_mgr.pool, dtype,
                                          shardings=self.ctx.pool_shardings())
             self.cache = self.ctx.place_replicated(
-                model.init_state_cache(engine_cfg.max_slots))
+                model.init_state_cache(engine_cfg.max_slots, dtype))
         else:
             pool_pages = engine_cfg.max_slots * (
                 -(-engine_cfg.max_len // ps)) + 1
@@ -212,12 +219,13 @@ class DuetEngine:
             self.max_pages = -(-engine_cfg.max_len // ps)
             self.pools = None
             self.cache = self.ctx.place_replicated(
-                model.init_cache(engine_cfg.max_slots, engine_cfg.max_len))
+                model.init_cache(engine_cfg.max_slots, engine_cfg.max_len,
+                                 dtype))
         # the multiplexer and the partition optimizer plan with the SAME
         # geometry the sharded programs execute: the mesh sets the
         # communication term's TP degree, and a TP replica spans tp chips
         self.mux = AdaptiveMultiplexer(
-            self.cfg, hw=hw,
+            self.cfg, hw=self.hw,
             total_units=max(engine_cfg.units, self._tp),
             tbt_slo=engine_cfg.tbt_slo, tp=self._tp,
             page_size=ps if self.paged else 1,

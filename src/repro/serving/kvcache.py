@@ -717,20 +717,23 @@ def init_page_pools(cfg: ArchConfig, pool: PagePoolConfig,
             f"{len(cfg.block_pattern)} layers")
     pools = []
     for i, kind in enumerate(cfg.block_pattern):
+        # allocate each pool where it lives: a full-size staging copy on the
+        # default device would not fit next to a replica's weights
+        dev = shardings[i] if shardings is not None else None
         if kind in GQA_KINDS:
             shape = (pool.num_pages, pool.page_size, cfg.num_kv_heads,
                      cfg.head_dim)
-            pools.append((jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)))
+            pools.append((jnp.zeros(shape, dtype, device=dev),
+                          jnp.zeros(shape, dtype, device=dev)))
         elif kind in MLA_KINDS:
             shape_c = (pool.num_pages, pool.page_size, cfg.kv_lora_rank)
             shape_r = (pool.num_pages, pool.page_size, cfg.qk_rope_dim)
-            pools.append((jnp.zeros(shape_c, dtype), jnp.zeros(shape_r, dtype)))
+            pools.append((jnp.zeros(shape_c, dtype, device=dev),
+                          jnp.zeros(shape_r, dtype, device=dev)))
         elif kind in RECURRENT_KINDS:
             pools.append(None)
         else:
             raise ValueError(f"init_page_pools: unknown block kind {kind!r}")
-        if shardings is not None and pools[-1] is not None:
-            pools[-1] = jax.device_put(pools[-1], shardings[i])
     return pools
 
 

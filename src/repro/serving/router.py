@@ -39,7 +39,7 @@ from dataclasses import dataclass
 from typing import Iterator, List, Optional, Sequence, Union
 
 from repro.core.device import DeviceContext
-from repro.core.roofline import HardwareSpec, TPU_V5E
+from repro.core.roofline import HardwareSpec
 from repro.models.transformer import Model
 from repro.serving.engine import DuetEngine, EngineConfig
 from repro.serving.request import Request, ServingMetrics
@@ -301,7 +301,7 @@ class Router:
                  replicas: Optional[int] = None,
                  policy: Union[str, DispatchPolicy] = "round-robin",
                  engine_cls=DuetEngine,
-                 hw: HardwareSpec = TPU_V5E, seed: int = 0,
+                 hw: Optional[HardwareSpec] = None, seed: int = 0,
                  elastic: Optional[ElasticConfig] = None):
         """Args:
             model / params / engine_cfg / hw / seed: forwarded to every

@@ -21,11 +21,12 @@ SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_config, reduced
     from repro.launch.dryrun import collective_bytes
+    from repro.launch.mesh import make_mesh
     from repro.models.params import (abstract_params, param_shardings,
                                      tp_adjusted_config)
     from repro.models.transformer import Model, cache_pspecs, cache_specs
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     results = {}
     for arch in ["qwen3-4b", "deepseek-v2-lite-16b", "zamba2-1.2b"]:
         cfg = tp_adjusted_config(reduced(get_config(arch)), 2)
@@ -61,6 +62,8 @@ SCRIPT = textwrap.dedent("""
 def test_small_mesh_multi_pod_lowering():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the child runs on host devices only: never on a chip its parent holds
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=540)
     assert proc.returncode == 0, proc.stderr[-3000:]

@@ -188,6 +188,8 @@ DRIVER = textwrap.dedent("""
 def parity():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the child runs on host devices only: never on a chip its parent holds
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", DRIVER], env=env,
                           capture_output=True, text=True, timeout=540)
     assert proc.returncode == 0, proc.stderr[-3000:]

@@ -77,3 +77,26 @@ def test_make_test_mesh_validates_device_count():
     with pytest.raises(ValueError, match="positive"):
         from repro.launch.mesh import make_test_mesh
         make_test_mesh(0, 1)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it an accelerator caches in the checkout's one fixed directory, and the
+    CPU backend caches nothing."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.enable_compile_cache() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == before
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache.enable_compile_cache() is None
+    monkeypatch.setattr(compile_cache.jax, "default_backend", lambda: "tpu")
+    try:
+        got = compile_cache.enable_compile_cache()
+        assert got == str(compile_cache.CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == got
+        assert compile_cache.CACHE_DIR.parent.joinpath("chip_smoke.py") \
+            .exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -99,3 +99,14 @@ def test_sliding_window_caps_attention():
     t_full = m_full.decode_latency(1, 500_000, units=1)
     t_win = m_win.decode_latency(1, 500_000, units=1)
     assert t_win < t_full / 5
+
+
+def test_hardware_spec_by_device_kind():
+    """The engine prices the device it runs on: v5e and the CPU test
+    target map to the v5e spec; an unknown TPU kind is an error, never a
+    silent v5e."""
+    from repro.core import hardware_spec
+    assert hardware_spec("TPU v5 lite") is TPU_V5E
+    assert hardware_spec("cpu") is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v7x"):
+        hardware_spec("TPU v7x")

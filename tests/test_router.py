@@ -39,7 +39,7 @@ DRIVER = textwrap.dedent("""
     import jax
     from repro.configs import get_config, reduced
     from repro.core.device import DeviceContext
-    from repro.launch.mesh import make_test_mesh, split_data_axis
+    from repro.launch.mesh import make_mesh, make_test_mesh, split_data_axis
     from repro.models.transformer import Model
     from repro.serving.async_engine import AsyncDuetEngine, TokenEvent
     from repro.serving.engine import DuetEngine, EngineConfig
@@ -141,7 +141,7 @@ DRIVER = textwrap.dedent("""
             == sorted(d.id for d in ctx.mesh.devices.flat),
         }
         try:
-            split_data_axis(jax.make_mesh((2, 2), ("model", "data")))
+            split_data_axis(make_mesh((2, 2), ("model", "data")))
             results["bad_axis_raises"] = False
         except ValueError:
             results["bad_axis_raises"] = True
@@ -167,6 +167,8 @@ DRIVER = textwrap.dedent("""
 def _drive(mode: str) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the child runs on host devices only: never on a chip its parent holds
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", DRIVER, mode], env=env,
                           capture_output=True, text=True, timeout=540)
     assert proc.returncode == 0, proc.stderr[-3000:]

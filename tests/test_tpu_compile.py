@@ -1,0 +1,89 @@
+"""The serving kernels compile for a TPU v5e at qwen3-4b widths.
+
+Each kernel of the main serving path is compiled ahead of time, in bf16,
+for one chip of a described ``v5e:2x2`` topology (no chip needed: the TPU
+compiler is installed with libtpu). Mosaic refuses what interpret mode
+accepts — unaligned blocks, unsupported vector reshapes — so these compiles
+catch a kernel the chip would reject before any chip time is spent. The
+topology is described inside a fixture, never at import, and the tests
+compile in this process (the TPU library is held by one process at a time).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import ops
+
+# qwen3-4b attention: 32 query heads over 8 KV heads of width 128
+H, G, DH = 32, 8, 128
+PAGE, PAGES, SLOTS, TABLE = 16, 1025, 8, 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # described-chip executables can be cached but never read back here
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _spec(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _paged_args(sh):
+    return (_spec(sh, (SLOTS, H, DH)), _spec(sh, (PAGES, PAGE, G, DH)),
+            _spec(sh, (PAGES, PAGE, G, DH)),
+            _spec(sh, (SLOTS, TABLE), jnp.int32),
+            _spec(sh, (SLOTS,), jnp.int32))
+
+
+def _assert_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_compiles(one_chip):
+    _assert_kernel(lambda *a: ops.paged_decode(*a, interpret=False),
+                   *_paged_args(one_chip))
+
+
+@pytest.mark.parametrize("num_splits", [2, 8])
+def test_paged_decode_splitkv_compiles(one_chip, num_splits):
+    _assert_kernel(lambda *a: ops.paged_decode_splitkv(
+        *a, num_splits=num_splits, interpret=False), *_paged_args(one_chip))
+
+
+@pytest.mark.parametrize("block_q", [1, 8])
+def test_duet_attention_paged_compiles(one_chip, block_q):
+    # the engine's fused step: 8 decode rows + a 256-token chunk, one row
+    # per tile (block_q=1) as Model.duet_step_paged dispatches it
+    rows = SLOTS + 256
+    sh = one_chip
+    _assert_kernel(
+        lambda *a: ops.duet_attention_paged(*a, block_q=block_q,
+                                            interpret=False),
+        _spec(sh, (rows, H, DH)), _spec(sh, (rows, 1), jnp.int32),
+        _spec(sh, (rows // block_q,), jnp.int32),
+        _spec(sh, (PAGES, PAGE, G, DH)), _spec(sh, (PAGES, PAGE, G, DH)),
+        _spec(sh, (rows, TABLE), jnp.int32))
+
+
+def test_flash_prefill_compiles(one_chip):
+    sh = one_chip
+    _assert_kernel(lambda *a: ops.flash_prefill(*a, interpret=False),
+                   _spec(sh, (1, 512, H, DH)), _spec(sh, (1, 512, G, DH)),
+                   _spec(sh, (1, 512, G, DH)))
