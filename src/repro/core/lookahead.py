@@ -32,6 +32,7 @@ def _freeze_inactive(new_cache, old_cache, active_mask: jax.Array):
     return jax.tree.map(merge, new_cache, old_cache)
 
 
+@jax.named_scope("sample")
 def _sample(logits: jax.Array, key: jax.Array, temperature: float):
     if temperature == 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -229,6 +230,13 @@ def make_superiter_fn(model: Model, kb: int, *, paged: bool, chunk: int = 0,
     ``sampled`` is the finishing prefill's next-token (or -1): the host
     fetches it together with ``toks`` in the single per-iteration sync.
 
+    The jitted function is named by kind, so a profile's ``XLA Modules``
+    line tells the programs apart: ``superiter_decode`` (chunk 0),
+    ``superiter_prefill`` (kb 0), ``superiter_mixed`` (the decode steps,
+    then the chunk) and ``superiter_duet`` (the fused duet grid). Inside,
+    named scopes mark ``decode_step``, ``prefill_chunk``, ``duet_grid`` and
+    ``sample``.
+
     ``ctx`` (:class:`repro.core.device.DeviceContext`): compile the fused
     program with explicit in/out shardings, so the whole super-iteration —
     k decode steps, the prefill chunk, in-program sampling, and the
@@ -243,6 +251,7 @@ def make_superiter_fn(model: Model, kb: int, *, paged: bool, chunk: int = 0,
         raise ValueError("duet_kernel needs paged mode with both phases "
                          "(kb > 0 and chunk > 0)")
 
+    @jax.named_scope("decode_step")
     def _decode(params, kvstate, last_tok, pos, tables, dkey, active):
         if paged:
             pools, state = kvstate
@@ -262,6 +271,7 @@ def make_superiter_fn(model: Model, kb: int, *, paged: bool, chunk: int = 0,
         last_tok = jnp.where(active[:, None], toks[:, -1:], last_tok)
         return toks, last_tok, pos, kvstate
 
+    @jax.named_scope("prefill_chunk")
     def _prefill(params, kvstate, last_tok, pos, pre_toks, pre_tbl,
                  pre_start, pre_slot, override_tok):
         if paged:
@@ -278,8 +288,9 @@ def make_superiter_fn(model: Model, kb: int, *, paged: bool, chunk: int = 0,
             kvstate = (_tree_write(cache, sub, pre_slot),)
         sampled = jnp.int32(-1)
         if finish:
-            tok = (jnp.argmax(logits[0]).astype(jnp.int32) if sample
-                   else override_tok)
+            with jax.named_scope("sample"):
+                tok = (jnp.argmax(logits[0]).astype(jnp.int32) if sample
+                       else override_tok)
             last_tok = jax.lax.dynamic_update_slice(
                 last_tok, tok[None, None], (pre_slot, 0))
             pos = jax.lax.dynamic_update_slice(
@@ -289,6 +300,7 @@ def make_superiter_fn(model: Model, kb: int, *, paged: bool, chunk: int = 0,
                 sampled = tok
         return sampled, last_tok, pos, kvstate
 
+    shardings = {}
     if duet_kernel:
         # Algorithm-1 fused grid: decode step 1 and the whole prefill chunk
         # execute as ONE duet_attention_paged launch per layer (decode rows
@@ -312,24 +324,28 @@ def make_superiter_fn(model: Model, kb: int, *, paged: bool, chunk: int = 0,
                 jnp.pad(tables, ((0, 0), (0, Wm - W))),
                 jnp.repeat(jnp.pad(pre_tbl, ((0, 0), (0, Wm - Wp))),
                            chunk, axis=0)])
-            logits, pools, state = model.duet_step_paged(
-                params, pools, state, row_tok, row_pos, row_tbl, order)
+            with jax.named_scope("duet_grid"):
+                logits, pools, state = model.duet_step_paged(
+                    params, pools, state, row_tok, row_pos, row_tbl, order)
             # decode step 1 retires inside the duet grid
             nxt = _sample(logits[:B], k_first, temperature)[:, None]
             nxt = jnp.where(active[:, None], nxt, last_tok)
             pos = jnp.where(active, pos + 1, pos)
             toks = nxt
             if kb > 1:
-                rest, pools, state, pos = lookahead_decode_paged(
-                    model, params, pools, state, nxt, pos, tables, kb - 1,
-                    key=k_rest, temperature=temperature, active_mask=active)
+                with jax.named_scope("decode_step"):
+                    rest, pools, state, pos = lookahead_decode_paged(
+                        model, params, pools, state, nxt, pos, tables,
+                        kb - 1, key=k_rest, temperature=temperature,
+                        active_mask=active)
                 toks = jnp.concatenate([nxt, rest], axis=1)
             last_tok = jnp.where(active[:, None], toks[:, -1:], last_tok)
             # the chunk's last row carries the prefill logits
             sampled = jnp.int32(-1)
             if finish:
-                tok = (jnp.argmax(logits[B + chunk - 1]).astype(jnp.int32)
-                       if sample else override_tok)
+                with jax.named_scope("sample"):
+                    tok = (jnp.argmax(logits[B + chunk - 1])
+                           .astype(jnp.int32) if sample else override_tok)
                 last_tok = jax.lax.dynamic_update_slice(
                     last_tok, tok[None, None], (pre_slot, 0))
                 pos = jax.lax.dynamic_update_slice(
@@ -343,8 +359,7 @@ def make_superiter_fn(model: Model, kb: int, *, paged: bool, chunk: int = 0,
         if ctx is not None:
             rep = ctx.replicated
             pool_sh = ctx.pool_shardings()
-            return jax.jit(
-                fused, donate_argnums=donate_argnums,
+            shardings = dict(
                 in_shardings=(ctx.param_shardings(), pool_sh) + (rep,) * 12,
                 out_shardings=(rep, rep, rep, rep, pool_sh, rep, rep))
     elif paged:
@@ -368,10 +383,8 @@ def make_superiter_fn(model: Model, kb: int, *, paged: bool, chunk: int = 0,
         if ctx is not None:
             rep = ctx.replicated
             pool_sh = ctx.pool_shardings()
-            return jax.jit(
-                fused, donate_argnums=donate_argnums,
-                in_shardings=(ctx.param_shardings(), pool_sh, rep, rep,
-                              rep, rep, rep, rep, rep, rep, rep, rep, rep),
+            shardings = dict(
+                in_shardings=(ctx.param_shardings(), pool_sh) + (rep,) * 11,
                 out_shardings=(rep, rep, rep, rep, pool_sh, rep, rep))
     else:
         def fused(params, cache, last_tok, pos, key, active,
@@ -393,9 +406,10 @@ def make_superiter_fn(model: Model, kb: int, *, paged: bool, chunk: int = 0,
         donate_argnums = (1, 2, 3) if donate else ()
         if ctx is not None:
             rep = ctx.replicated
-            return jax.jit(
-                fused, donate_argnums=donate_argnums,
-                in_shardings=(ctx.param_shardings(), rep, rep, rep, rep,
-                              rep, rep, rep, rep, rep),
-                out_shardings=(rep, rep, rep, rep, rep, rep))
-    return jax.jit(fused, donate_argnums=donate_argnums)
+            shardings = dict(
+                in_shardings=(ctx.param_shardings(),) + (rep,) * 9,
+                out_shardings=(rep,) * 6)
+    kind = ("duet" if duet_kernel else "decode" if chunk == 0
+            else "prefill" if kb == 0 else "mixed")
+    fused.__name__ = fused.__qualname__ = f"superiter_{kind}"
+    return jax.jit(fused, donate_argnums=donate_argnums, **shardings)

@@ -160,6 +160,7 @@ def duet_attention(q, row_pos, tile_slot, k_slab, v_slab, *,
         ),
         out_shape=jax.ShapeDtypeStruct((R, H, Dh), q.dtype),
         interpret=interpret,
+        name="duet_attention",
     )(tile_slot.astype(jnp.int32), q, _tile_rows(row_pos, T, block_q),
       k_slab, v_slab)
     return out
@@ -247,6 +248,7 @@ def duet_attention_paged(q, row_pos, tile_slot, k_pages, v_pages, tables, *,
         ),
         out_shape=jax.ShapeDtypeStruct((R, H, Dh), q.dtype),
         interpret=interpret,
+        name="duet_attention_paged",
     )(tile_slot.astype(jnp.int32), tables.astype(jnp.int32), q,
       _tile_rows(row_pos, T, block_q), k_pages, v_pages)
     return out

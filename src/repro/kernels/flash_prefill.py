@@ -120,5 +120,6 @@ def flash_prefill(q, k, v, *, q_offset=0, kv_len=None, block_q: int = 128,
         out_shape=jax.ShapeDtypeStruct((B, G, Sq // block_q * block_q, rep,
                                         Dh), q.dtype),
         interpret=interpret,
+        name="flash_prefill",
     )(jnp.asarray([q_offset], jnp.int32), qg, kg, vg)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, Sq, H, Dh)

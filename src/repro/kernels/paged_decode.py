@@ -118,6 +118,7 @@ def paged_decode(q, k_pages, v_pages, tables, lengths, *,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pages,
       v_pages)
     return out
@@ -228,5 +229,6 @@ def paged_decode_splitkv(q, k_pages, v_pages, tables, lengths, *,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
         interpret=interpret,
+        name="paged_decode_splitkv",
     )(tbl, lengths.astype(jnp.int32), q, k_pages, v_pages)
     return out

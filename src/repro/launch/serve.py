@@ -13,7 +13,11 @@ virtual clock, which advances by the roofline's prediction for the device
 (serving/engine.py) — they are predictions on every platform, the chip
 included. ``wall_setup_s`` / ``wall_serve_s`` are the host clock: parameter
 init plus engine construction, then serving (jit compiles included) up to
-the last device output.
+the last device output. With ``--stream`` the summary adds
+``measured_{mean,p50,p99}_ttft_s`` and ``measured_{mean,p50,p99}_req_tbt_s``
+on the host clock, from the stage stamps the async engine puts on each
+request (compiles included, as in ``wall_serve_s``): TTFT, and statistics
+over requests of each request's mean token gap, which is not a TBT tail.
 
 Two execution modes:
 

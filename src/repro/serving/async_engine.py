@@ -31,6 +31,21 @@ streaming admission.
   :meth:`events` (generator), :meth:`run` (callback), or :meth:`astream`
   (async iterator).
 
+* **Host spans and stage stamps** — each phase of a super-iteration is one
+  flat ``jax.profiler.TraceAnnotation`` on the profiler's clock, so a
+  trace puts the host's phases beside the device's programs:
+  ``engine.plan`` (ingest, admission, planning and page bookkeeping up to
+  the first dispatch), ``engine.tiers`` (tier migrations), one
+  ``engine.dispatch`` per program (argument preparation and the jitted
+  call; ``engine.compile`` nested inside on a dispatch-cache miss),
+  ``engine.fetch`` (the one blocking ``device_get``) and ``engine.emit``
+  (building events, completing demotions). Every span carries ``si``, the
+  super-iteration index; a dispatch adds ``kb``, ``chunk``, ``duet`` and
+  ``predicted_ms``, the roofline's span of its super-iteration. The spans
+  cost about a microsecond each when no profiler session is active.
+  Requests carry host-clock stage times in ``Request.stamps``, and
+  ``dstats.predicted_s`` sums the roofline's spans.
+
 The synchronous engine remains the token-equivalence oracle: greedy decode
 makes the async engine's output streams token-identical on the same trace
 (``tests/test_async_engine.py``), on both the paged and the slab path.
@@ -38,7 +53,9 @@ makes the async engine's output streams token-identical on the same trace
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import threading
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Iterator, List, Optional, Sequence, Union
@@ -46,6 +63,7 @@ from typing import Callable, Iterator, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import GQA_KINDS
 from repro.core.device import DeviceContext
@@ -61,19 +79,23 @@ from repro.serving.scheduler import IterationPlan
 # --------------------------------------------------------------------- events
 @dataclass(frozen=True)
 class TokenEvent:
-    """One generated token, streamed as soon as its iteration retires."""
+    """One generated token, streamed as soon as its iteration retires.
+    ``t`` is the emission time the roofline predicts, on the engine's
+    virtual clock, not a measured time (``Request.stamps`` holds the host
+    clock's)."""
     rid: int
     token: int
     index: int          # position in the request's output stream
-    t: float            # virtual-clock emission time
+    t: float            # predicted emission time (virtual clock)
 
 
 @dataclass(frozen=True)
 class FinishEvent:
-    """Terminal outcome of a request (completed or rejected)."""
+    """Terminal outcome of a request (completed or rejected). ``t`` is a
+    predicted time on the virtual clock, as in :class:`TokenEvent`."""
     rid: int
     reason: str         # "completed" | "rejected:<why>"
-    t: float
+    t: float            # predicted time (virtual clock)
     n_tokens: int
     output_tokens: List[int] = field(default_factory=list)
 
@@ -89,6 +111,7 @@ class DispatchStats:
     host_syncs: int = 0          # blocking device->host fetches
     cache_hits: int = 0          # dispatches served by a cached program
     cache_misses: int = 0        # dispatches that compiled a new bucket
+    predicted_s: float = 0.0     # roofline's forecast of the iterations run
 
     @property
     def syncs_per_super_iteration(self) -> float:
@@ -115,6 +138,7 @@ class _Inflight:
     """Device handles + host metadata of one dispatched super-iteration.
     The handles are program *outputs* captured at dispatch, so later
     programs can run before this record is drained."""
+    si: int = 0                  # super-iteration index (span argument)
     fetch: List[jax.Array] = field(default_factory=list)
     toks_idx: int = -1
     dec_items: List[_DecItem] = field(default_factory=list)
@@ -184,10 +208,12 @@ class AsyncDuetEngine(DuetEngine):
         if isinstance(requests, Request):
             requests = [requests]
         reqs = list(requests)
+        host_t = time.perf_counter()
         for r in reqs:
             self._materialize_prompt(r)
             if at is not None:
                 r.arrival = at
+            r.stamps.setdefault("submit", host_t)
         with self._lock:
             self._inbox.extend(reqs)
 
@@ -201,8 +227,10 @@ class AsyncDuetEngine(DuetEngine):
         self._pending.extend(new)
         self._pending.sort(key=lambda r: r.arrival)
 
-    def _finish_event(self, r: Request,
-                      t: Optional[float] = None) -> FinishEvent:
+    def _finish_event(self, r: Request, t: Optional[float] = None,
+                      host_t: Optional[float] = None) -> FinishEvent:
+        r.stamps["finish"] = time.perf_counter() if host_t is None \
+            else host_t
         return FinishEvent(r.rid, r.finish_reason or "completed",
                            self.now if t is None else t,
                            len(r.output_tokens), list(r.output_tokens))
@@ -269,15 +297,19 @@ class AsyncDuetEngine(DuetEngine):
     def _tick(self):
         """One pass of the async serving loop (shared ``(events,
         progressed)`` contract with the base engine, so ``service_until``
-        can drive either engine class)."""
+        can drive either engine class). An idle pass's ``engine.plan``
+        span carries the index of the super-iteration that follows it."""
         evs: List[Event] = []
-        self._ingest()
-        self.state.admit_arrivals(self._pending, self.now)
-        for r in self._admit_waiting():
-            evs.append(self._finish_event(r))
-        plan = self._plan()
-        if not plan.is_idle:
-            evs.extend(self._step(plan))
+        si = self.dstats.super_iterations + 1
+        with TraceAnnotation("engine.plan", si=si):
+            self._ingest()
+            self.state.admit_arrivals(self._pending, self.now)
+            for r in self._admit_waiting():
+                evs.append(self._finish_event(r))
+            plan = self._plan()
+            staged = None if plan.is_idle else self._stage(plan, si, evs)
+        if staged is not None:
+            evs.extend(self._launch(si, *staged))
             return evs, True
         # idle: flush the pipeline, then wait for arrivals or stop
         evs.extend(self._drain())
@@ -341,11 +373,15 @@ class AsyncDuetEngine(DuetEngine):
         self._tier_captures = []
 
     # -------------------------------------------------------- super-iteration
-    def _step(self, plan: IterationPlan) -> Iterator[Event]:
-        """Plan + dispatch one super-iteration, then drain the previous one.
-        Bookkeeping is completion-deterministic (greedy decode), so the
-        whole plan is built from host state while the previous iteration is
-        still executing on device."""
+    def _stage(self, plan: IterationPlan, si: int, evs: List[Event]):
+        """Host half of super-iteration ``si``: the page and token
+        bookkeeping of ``plan``, up to the dispatch inputs. Bookkeeping is
+        completion-deterministic (greedy decode), so the whole plan is
+        built from host state while the previous iteration is still
+        executing on device. Events of a catch-up drain go to ``evs``
+        (its fetch and emit spans then nest in ``engine.plan``).
+        Returns ``(kb, ran, dec_items, dec_args, pre_items, t_p, span)``
+        for :meth:`_launch`."""
         self.dstats.super_iterations += 1
         # a preemption-resume chunk that replays already-sampled outputs
         # (its token slice reaches past the prompt, or it finishes and
@@ -355,12 +391,13 @@ class AsyncDuetEngine(DuetEngine):
         if any(r.resume_len and (r.prefilled + c > r.prompt_len
                                  or c >= r.remaining_prompt)
                for r, c in plan.prefill):
-            yield from self._drain()
+            evs.extend(self._drain())
 
         k, t_d, t_p = self._iteration_timing(plan)
 
         kb, ran = (self._plan_decode_batch(plan.decode, k)
                    if plan.decode else (0, []))
+        span = self._iteration_span(plan, kb, t_d, t_p)
         self._privatize_decode_pages(ran)
         # duet fusion safety: a decode request finishing inside this
         # iteration returns its pages to the pool below, and the prefill
@@ -436,23 +473,30 @@ class AsyncDuetEngine(DuetEngine):
                     self._retire(r)
                 else:
                     self.state.running.append(r)
+        return kb, ran, dec_items, dec_args, pre_items, t_p, span
 
+    def _launch(self, si: int, kb: int, ran, dec_items, dec_args,
+                pre_items, t_p: float, span: float) -> List[Event]:
+        """Device half of super-iteration ``si``: dispatch its programs,
+        drain the previous super-iteration, and advance the virtual clock
+        by the roofline's ``span``."""
         # device dispatch: decode fuses with the first prefill chunk into
         # one program; extra chunks ride prefill-only programs (more
         # dispatches, still zero extra host syncs)
-        inf = _Inflight(dec_items=dec_items)
+        inf = _Inflight(si=si, dec_items=dec_items)
         if ran or pre_items:
             self._dispatch(inf, kb if ran else 0, dec_args,
-                           pre_items[0] if pre_items else None, t_p)
+                           pre_items[0] if pre_items else None, t_p, span)
             for item in pre_items[1:]:
-                self._dispatch(inf, 0, None, item, t_p)
+                self._dispatch(inf, 0, None, item, t_p, span)
         # demotion slices captured while planning/dispatching this
         # iteration ride its batched device_get — zero extra host syncs
         self._attach_tier_captures(inf)
         prev, self._inflight = self._inflight, (inf if inf.fetch else None)
-        if prev is not None:
-            yield from self._drain_record(prev)
-        self.now += self._iteration_span(plan, kb, t_d, t_p)
+        evs = self._drain_record(prev) if prev is not None else []
+        self.dstats.predicted_s += span
+        self.now += span
+        return evs
 
     # ---------------------------------------------------------------- device
     def _program(self, key, kb, chunk, finish, sample, duet=False):
@@ -485,13 +529,23 @@ class AsyncDuetEngine(DuetEngine):
         return order
 
     def _dispatch(self, inf: _Inflight, kb: int, dec_args, pre_item,
-                  t_p: float):
+                  t_p: float, span: float):
         """Launch one fused program; capture its output handles in `inf`.
         Everything here is host->device only — no blocking reads."""
         # flush tier migrations first: promoted pages must hold their
         # content before this program can read them, and pending demotion
         # slices must be enqueued before the program rewrites their pages
-        self._service_tiers()
+        with TraceAnnotation("engine.tiers", si=inf.si):
+            self._service_tiers()
+        chunk = pre_item[1] if pre_item is not None else 0
+        duet = (self._duet_kernel and self._duet_safe
+                and kb > 0 and chunk > 0)
+        with TraceAnnotation("engine.dispatch", si=inf.si, kb=kb,
+                             chunk=chunk, duet=duet, predicted_ms=1e3 * span):
+            self._call_program(inf, kb, dec_args, pre_item, t_p, duet)
+
+    def _call_program(self, inf: _Inflight, kb: int, dec_args, pre_item,
+                      t_p: float, duet: bool):
         B = self.ec.max_slots
         if dec_args is None:
             dec_args = (np.zeros(B, bool), np.zeros((B, 1), np.int32), 1)
@@ -521,12 +575,16 @@ class AsyncDuetEngine(DuetEngine):
             pre_slot = jnp.int32(0)
             override = jnp.int32(0)
 
-        duet = (self._duet_kernel and self._duet_safe
-                and kb > 0 and chunk > 0)
         key = (self.paged, kb, width if kb else 0, chunk,
                pwidth if chunk else 0, finish, sample, duet)
+        built = key not in self._programs
         prog = self._program(key, kb, chunk, finish, sample, duet)
         self.dstats.dispatches += 1
+        if pre_item is not None:
+            pre_item[0].stamps.setdefault("first_dispatch",
+                                          time.perf_counter())
+        compiling = (TraceAnnotation("engine.compile", si=inf.si) if built
+                     else contextlib.nullcontext())
         if self.paged:
             pargs = (self.params, self.pools, self.cache, self.d_last_tok,
                      self.d_pos, jnp.asarray(tbl), self.d_key,
@@ -534,14 +592,16 @@ class AsyncDuetEngine(DuetEngine):
                      pre_slot, override)
             if duet:
                 pargs += (jnp.asarray(self._duet_order(chunk)),)
-            (toks, sampled, self.d_last_tok, self.d_pos, self.pools,
-             self.cache, self.d_key) = prog(*pargs)
+            with compiling:
+                (toks, sampled, self.d_last_tok, self.d_pos, self.pools,
+                 self.cache, self.d_key) = prog(*pargs)
         else:
-            (toks, sampled, self.d_last_tok, self.d_pos, self.cache,
-             self.d_key) = prog(
-                self.params, self.cache, self.d_last_tok, self.d_pos,
-                self.d_key, jnp.asarray(active), pre_toks, pre_start,
-                pre_slot, override)
+            with compiling:
+                (toks, sampled, self.d_last_tok, self.d_pos, self.cache,
+                 self.d_key) = prog(
+                    self.params, self.cache, self.d_last_tok, self.d_pos,
+                    self.d_key, jnp.asarray(active), pre_toks, pre_start,
+                    pre_slot, override)
         if kb > 0:
             inf.toks_idx = len(inf.fetch)
             inf.fetch.append(toks)
@@ -551,41 +611,49 @@ class AsyncDuetEngine(DuetEngine):
             inf.fetch.append(sampled)
 
     # ----------------------------------------------------------------- drain
-    def _drain(self) -> Iterator[Event]:
+    def _drain(self) -> List[Event]:
         inf, self._inflight = self._inflight, None
-        if inf is not None:
-            yield from self._drain_record(inf)
+        return self._drain_record(inf) if inf is not None else []
 
-    def _drain_record(self, inf: _Inflight) -> Iterator[Event]:
+    def _drain_record(self, inf: _Inflight) -> List[Event]:
         """Retire one dispatched super-iteration: fetch every device value it
         produced in a single blocking sync, append token values to their
-        requests, and emit stream events."""
+        requests, and build its stream events. One host-clock reading,
+        taken when the values arrive, stamps all of its requests."""
         if not inf.fetch:
-            return
-        vals = jax.device_get(inf.fetch)
+            return []
+        with TraceAnnotation("engine.fetch", si=inf.si):
+            vals = jax.device_get(inf.fetch)
+        host_t = time.perf_counter()
         self.dstats.host_syncs += 1
-        if inf.toks_idx >= 0:
-            toks = np.asarray(vals[inf.toks_idx])
-            for it in inf.dec_items:
-                seq = toks[it.slot, :len(it.times)]
-                base = len(it.req.output_tokens)
-                it.req.output_tokens.extend(int(t) for t in seq)
-                for j, (tok, ts) in enumerate(zip(seq, it.times)):
-                    yield TokenEvent(it.req.rid, int(tok), base + j, ts)
-                yield from self._maybe_finish(it.req)
-        for fi in inf.first_items:
-            tok = int(vals[fi.fetch_idx])
-            yield TokenEvent(fi.req.rid, tok, len(fi.req.output_tokens),
-                             fi.ts)
-            fi.req.output_tokens.append(tok)
-            yield from self._maybe_finish(fi.req)
-        for key, layout in inf.demotions:
-            self.kv_mgr.complete_demotion(key, [
-                None if pair is None else (np.asarray(vals[pair[0]]),
-                                           np.asarray(vals[pair[1]]))
-                for pair in layout])
+        evs: List[Event] = []
+        with TraceAnnotation("engine.emit", si=inf.si):
+            if inf.toks_idx >= 0:
+                toks = np.asarray(vals[inf.toks_idx])
+                for it in inf.dec_items:
+                    seq = toks[it.slot, :len(it.times)]
+                    base = len(it.req.output_tokens)
+                    it.req.output_tokens.extend(int(t) for t in seq)
+                    evs.extend(
+                        TokenEvent(it.req.rid, int(tok), base + j, ts)
+                        for j, (tok, ts) in enumerate(zip(seq, it.times)))
+                    evs.extend(self._maybe_finish(it.req, host_t))
+            for fi in inf.first_items:
+                tok = int(vals[fi.fetch_idx])
+                fi.req.stamps.setdefault("first_fetch", host_t)
+                evs.append(TokenEvent(fi.req.rid, tok,
+                                      len(fi.req.output_tokens), fi.ts))
+                fi.req.output_tokens.append(tok)
+                evs.extend(self._maybe_finish(fi.req, host_t))
+            for key, layout in inf.demotions:
+                self.kv_mgr.complete_demotion(key, [
+                    None if pair is None else (np.asarray(vals[pair[0]]),
+                                               np.asarray(vals[pair[1]]))
+                    for pair in layout])
+        return evs
 
-    def _maybe_finish(self, r: Request) -> Iterator[Event]:
+    def _maybe_finish(self, r: Request, host_t: float) -> List[Event]:
         if r.phase == Phase.FINISHED and \
                 len(r.output_tokens) >= r.output_len:
-            yield self._finish_event(r, t=r.finish_time)
+            return [self._finish_event(r, t=r.finish_time, host_t=host_t)]
+        return []

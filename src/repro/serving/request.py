@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import enum
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -57,9 +57,15 @@ class Request:
     finish_reason: Optional[str] = None   # "completed" | "rejected:<why>"
 
     # metrics ---------------------------------------------------------------
+    # virtual clock: the times the roofline predicts for each token
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     token_times: List[float] = field(default_factory=list)
+    # host clock (``time.perf_counter``), set by the async engine: "submit",
+    # "first_dispatch" (its first prefill chunk dispatched; kept across
+    # preemption), "first_fetch" (its first token value on the host),
+    # "finish"
+    stamps: Dict[str, float] = field(default_factory=dict)
 
     @property
     def prefill_total(self) -> int:
@@ -114,6 +120,21 @@ class Request:
     def tbt_samples(self) -> List[float]:
         return [b - a for a, b in zip(self.token_times, self.token_times[1:])]
 
+    def measured_ttft(self) -> Optional[float]:
+        """Host-clock submit to first token value, from the stamps."""
+        s = self.stamps
+        if "submit" not in s or "first_fetch" not in s:
+            return None
+        return s["first_fetch"] - s["submit"]
+
+    def measured_tbt(self) -> Optional[float]:
+        """Host-clock mean gap between this request's tokens, from the
+        stamps: first token value to finish over the gaps between."""
+        s = self.stamps
+        if "first_fetch" not in s or "finish" not in s or self.generated < 2:
+            return None
+        return (s["finish"] - s["first_fetch"]) / (self.generated - 1)
+
 
 @dataclass
 class ServingMetrics:
@@ -144,13 +165,19 @@ class ServingMetrics:
         return ok / len(self.requests)
 
     def summary(self) -> dict:
+        """Counts, and TTFT/TBT/throughput on the engine's virtual clock:
+        the roofline's predictions. Where requests carry the async
+        engine's host-clock stamps, ``measured_*`` keys add TTFT measured
+        from them, and ``measured_*_req_tbt_s`` the mean, median and 99th
+        percentile over requests of each request's mean token gap (a
+        stall that hits a few gaps barely moves them: not a TBT tail)."""
         finished = [r for r in self.requests if r.finish_time is not None]
         ttfts = [r.ttft() for r in finished if r.ttft() is not None]
         tbts = [t for r in finished for t in r.tbt_samples()]
         out_tokens = sum(r.generated for r in self.requests)
         total_tokens = out_tokens + sum(r.prefilled for r in self.requests)
         dur = max(self.duration, 1e-9)
-        return {
+        out = {
             "num_finished": len(finished),
             "num_requests": len(self.requests),
             "num_rejected": sum(1 for r in self.requests
@@ -175,6 +202,15 @@ class ServingMetrics:
             "total_token_throughput": total_tokens / dur,
             "duration_s": self.duration,
         }
+        for name, vals in (
+                ("ttft", [r.measured_ttft() for r in finished]),
+                ("req_tbt", [r.measured_tbt() for r in finished])):
+            vals = [v for v in vals if v is not None]
+            if vals:
+                out[f"measured_mean_{name}_s"] = sum(vals) / len(vals)
+                out[f"measured_p50_{name}_s"] = _pct(vals, 0.50)
+                out[f"measured_p99_{name}_s"] = _pct(vals, 0.99)
+        return out
 
 
 def _pct(xs: List[float], p: float) -> float:

@@ -404,6 +404,17 @@ def test_donation_real_tree_clean():
     assert report.findings == []
 
 
+def test_donation_real_engine_program_is_a_donating_factory():
+    # the clean run above checks the engine's dispatch only while its
+    # `_program` resolves, through make_superiter_fn, as a donating factory
+    from tools.duetlint.rules.donation import _factories
+    targets = [os.path.join(REPO, "src/repro/core/lookahead.py"),
+               os.path.join(REPO, "src/repro/serving/async_engine.py")]
+    factories = _factories(Project.from_paths(targets))
+    assert factories["make_superiter_fn"]
+    assert factories["_program"] == factories["make_superiter_fn"]
+
+
 # ---------------------------------------------------------------------------
 # rule 6: pallas-hygiene
 

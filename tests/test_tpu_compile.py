@@ -87,3 +87,48 @@ def test_flash_prefill_compiles(one_chip):
     _assert_kernel(lambda *a: ops.flash_prefill(*a, interpret=False),
                    _spec(sh, (1, 512, H, DH)), _spec(sh, (1, 512, G, DH)),
                    _spec(sh, (1, 512, G, DH)))
+
+
+def test_paged_decode_superiteration_names_its_kernel(one_chip, monkeypatch):
+    """The engine's depth-1 paged decode super-iteration, at qwen3-4b
+    widths with 2 layers and 32 slots over 128-page tables: each layer's
+    attention custom-call carries the kernel's own name, which a device
+    trace reports as the op family, and no instruction is named after the
+    scan body that holds it (``closed_call``)."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.core.lookahead import make_superiter_fn
+    from repro.models import Model
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("qwen3-4b"), num_layers=2,
+                              block_pattern=())
+    model = Model(cfg, attn_kernel=True)
+    B, sh = 32, one_chip
+
+    def specs(tree, dtype=None):
+        return jax.tree.map(
+            lambda x: _spec(sh, x.shape, dtype or x.dtype), tree)
+
+    params = specs(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                   jnp.bfloat16)
+    pool = _spec(sh, (PAGES, PAGE, G, DH))
+    state = specs(jax.eval_shape(
+        lambda: model.init_state_cache(B, jnp.bfloat16)))
+    i32 = jnp.int32
+    args = (params, [(pool, pool)] * cfg.num_layers, state,
+            _spec(sh, (B, 1), i32), _spec(sh, (B,), i32),
+            _spec(sh, (B, TABLE), i32), _spec(sh, (2,), jnp.uint32),
+            _spec(sh, (B,), jnp.bool_), _spec(sh, (1, 1), i32),
+            _spec(sh, (1, 1), i32), _spec(sh, (), i32), _spec(sh, (), i32),
+            _spec(sh, (), i32))
+    prog = make_superiter_fn(model, 1, paged=True, donate=False)
+    text = prog.lower(*args).compile().as_text()
+    calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == cfg.num_layers
+    assert all(re.fullmatch(r"paged_decode(_splitkv)?\.\d+", c)
+               for c in calls), calls
+    assert not re.search(r"%closed_call[.\d]* = ", text)
