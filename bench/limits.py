@@ -57,7 +57,7 @@ def readings(cell: Cell, seeds, seconds: float, require_chip: bool = True):
             # one set of weights on the chip at a time
             engine.params = None
             gc.collect()
-        params = make_weights(cell.config, seed)
+        params = make_weights(cell.arch, cell.config, seed)
         if engine is None:
             engine = build_engine(cell, params)
         else:
@@ -82,10 +82,10 @@ def readings(cell: Cell, seeds, seconds: float, require_chip: bool = True):
         prog = ctl = 0.0
         n = 0
         for r in sample:
-            c = reference.control_tokens(params, cell.config, r.prompt,
-                                         r.toks)
-            g = reference.gaps(params, cell.config, r.prompt, r.toks,
-                               {"control": c})
+            c = reference.control_tokens(cell.arch, params, cell.config,
+                                         r.prompt, r.toks)
+            g = reference.gaps(cell.arch, params, cell.config, r.prompt,
+                               r.toks, {"control": c})
             prog = max(prog, float(g["served"].max()))
             ctl = max(ctl, float(g["control"].max()))
             n += len(c)

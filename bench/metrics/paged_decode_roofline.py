@@ -2,12 +2,13 @@
 and ``paged_decode_splitkv``), in percent: the least time the chip could
 take for the decode attention the window's tokens need, over the kernel's
 device time. The least time is the larger of ``decode_attn_flops`` over
-the bf16 peak and ``decode_attn_bytes`` over the HBM peak
-(``bench/work.py`` through ``readings.window_work``, from context lengths,
-never from padded shapes). Bytes set it: per cached position a query
-does 4 * H * dh FLOPs and reads 2 * G * dh bfloat16 elements of K and V,
-H / G FLOPs per byte (4 for Qwen3's 32 query heads over 8 KV heads),
-against the v5e's 240. Layer: kernels (``kernels/paged_decode``). Read
+the bf16 peak and ``decode_attn_bytes`` over the HBM peak (the
+architecture's ``attn_flops`` and ``attn_bytes``, ``bench/arch/``, through
+``readings.window_work``, from context lengths, never from padded
+shapes). Bytes set it for dense GQA: per cached position a query does
+4 * H * dh FLOPs and reads 2 * G * dh bfloat16 elements of K and V, H / G
+FLOPs per byte (4 for Qwen3's 32 query heads over 8 KV heads), against
+the v5e's 240. Layer: kernels (``kernels/paged_decode``). Read
 only where every decode row goes through this kernel, so the counted work
 is the kernel's: None where the window ran a duet iteration (its decode
 rows go through the duet grid), the trace has no such kernel, or the

@@ -72,10 +72,11 @@ def end_to_end(recs: List[Record], t0: float, t1: float,
     }
 
 
-def window_work(recs: List[Record], cfg: Dict, t0: float, t1: float,
+def window_work(recs: List[Record], arch, cfg: Dict, t0: float, t1: float,
                 pre0: Dict[int, int], pre1: Dict[int, int]
                 ) -> Dict[str, float]:
-    """Work the window's tokens require (``bench/work.py``): the prompt
+    """Work the window's tokens require under ``arch``, the configuration's
+    architecture module (``bench/work.py``): the prompt
     positions dispatched between t0 and t1 (``pre0``, ``pre1``: prompt
     tokens dispatched by then, per request), and each decode token
     delivered in [t0, t1] (token j >= 1 attends prompt_len + j positions).
@@ -86,16 +87,16 @@ def window_work(recs: List[Record], cfg: Dict, t0: float, t1: float,
     for r in recs:
         a, b = pre0.get(r.rid, 0), pre1.get(r.rid, 0)
         if b > a:
-            flops += work.prefill_flops(cfg, b - a, start=a,
+            flops += work.prefill_flops(arch, cfg, b - a, start=a,
                                         head=b == r.prompt_len)
             prompt_tokens += b - a
         for j, t in enumerate(r.tok_t):
             if j == 0 or not t0 <= t <= t1:
                 continue
             ctx = r.prompt_len + j
-            flops += work.decode_flops(cfg, ctx)
-            attn_f += work.attn_flops(cfg, ctx)
-            attn_b += work.attn_bytes(cfg, ctx)
+            flops += work.decode_flops(arch, cfg, ctx)
+            attn_f += arch.attn_flops(cfg, ctx)
+            attn_b += arch.attn_bytes(cfg, ctx)
             decode_tokens += 1
     return {"flops": flops, "decode_attn_flops": attn_f,
             "decode_attn_bytes": attn_b, "decode_tokens": decode_tokens,

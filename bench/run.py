@@ -4,13 +4,16 @@
 
 The cell (an entry of ``workloads`` in BENCHMARK.json) names a model
 configuration (its ``file``) and a traffic mix (``bench/traffic/<mix>.json``).
-The run:
+The configuration's ``model_type`` names its architecture's module
+(``bench/arch/<model_type>.py``), which gives the weight tree, the
+program's ``ArchConfig``, the reference and the work counts. The run:
 
 1. refuses to run without a TPU, or with fewer chips than the cell asks
    for: exit code 3, nothing on stdout;
-2. makes bfloat16 weights on the device from the seed (``bench/model.py``)
-   and builds the deployed serving path: ``AsyncDuetEngine`` over
-   ``Model(attn_kernel=True)``, paged KV pools, prefix cache on;
+2. makes bfloat16 weights of the architecture's tree on the device from
+   the seed (``bench/model.py``) and builds the deployed serving path:
+   ``AsyncDuetEngine`` over ``Model(attn_kernel=True)``, paged KV pools,
+   prefix cache on;
 3. warms up by serving the window's own request lengths (with other prompt
    tokens) for longer than the window lasts, so every program the window
    dispatches is compiled; then empties the engine with its public
@@ -21,10 +24,10 @@ The run:
    when its previous one finishes. Every time is the host clock when an
    event reaches the client;
 5. after the window, frees the engine and compares a seeded sample of the
-   requests finished in the window with the float32 reference
-   (``bench/reference.py``): ``correct`` holds when the widest gap between
-   the reference's best logit and its logit of a served token is within
-   the configuration's limit;
+   requests finished in the window with the architecture's float32
+   reference (``bench/reference.py``): ``correct`` holds when the widest
+   gap between the reference's best logit and its logit of a served token
+   is within the configuration's limit;
 6. prints, as the last line of stdout, one JSON object: ``correct``,
    ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics
    with ``--trace 0``, its per-layer metrics with ``--trace 1``),
@@ -48,6 +51,7 @@ import sys  # noqa: E402
 import tempfile  # noqa: E402
 from dataclasses import dataclass  # noqa: E402
 from pathlib import Path  # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Dict, List  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -57,7 +61,7 @@ if __name__ == "__main__":
     sys.path.insert(1, str(ROOT / "src"))
 
 from bench import loadgen, readings  # noqa: E402
-from bench.model import load_config  # noqa: E402
+from bench.model import load_arch, load_config  # noqa: E402
 
 WARM_FACTOR = 1.25    # warm-up serves this many windows' worth of work
 GRACE_S = 90.0        # most serving after the window to finish requests
@@ -75,6 +79,7 @@ class Cell:
     name: str
     chips: int
     config: Dict
+    arch: ModuleType
     config_name: str
     mix: loadgen.Mix
     end_to_end: List[Dict]
@@ -89,11 +94,12 @@ class Cell:
             raise KeyError(f"no workload {name!r}; have {sorted(cells)}")
         w = cells[name]
         c = {x["name"]: x for x in spec["configs"]}[w["config"]]
+        config = load_config(root / c["file"])
 
         def mine(m):
             return "workloads" not in m or name in m["workloads"]
-        return cls(name=name, chips=int(w["chips"]),
-                   config=load_config(root / c["file"]),
+        return cls(name=name, chips=int(w["chips"]), config=config,
+                   arch=load_arch(root, config, c["file"]),
                    config_name=c["name"],
                    mix=loadgen.Mix.load(
                        root / "bench" / "traffic" / f"{w['traffic']}.json"),
@@ -236,9 +242,8 @@ def build_engine(cell: Cell, params):
     from repro.models.transformer import Model
     from repro.serving.async_engine import AsyncDuetEngine
     from repro.serving.engine import EngineConfig
-    from bench.model import arch_config
     e = cell.config["engine"]
-    model = Model(arch_config(cell.config, cell.config_name),
+    model = Model(cell.arch.arch_config(cell.config, cell.config_name),
                   attn_kernel=True)
     ec = EngineConfig(max_slots=e["max_slots"],
                       token_budget=e["token_budget"], tbt_slo=e["tbt_slo"],
@@ -291,7 +296,8 @@ def check(cell: Cell, params, sample: List[readings.Record]) -> Dict:
     from bench import reference
     widest, n = 0.0, 0
     for r in sample:
-        g = reference.gaps(params, cell.config, r.prompt, r.toks)["served"]
+        g = reference.gaps(cell.arch, params, cell.config, r.prompt,
+                           r.toks)["served"]
         widest = max(widest, float(g.max()))
         n += len(g)
     return {"widest": widest, "tokens": n, "requests": len(sample)}
@@ -338,7 +344,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     clock = CompileClock().install()
 
     t = time.perf_counter()
-    params = make_weights(cell.config, seed)
+    params = make_weights(cell.arch, cell.config, seed)
     jax.block_until_ready(params)
     log(f"weights {time.perf_counter() - t:.2f} s")
     t = time.perf_counter()
@@ -438,8 +444,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     else:
         r = readings.Readings(
             window_s=t1 - t0, counters=delta, peak=peak,
-            work=readings.window_work(recs, cell.config, t0, t1,
-                                      pre0, pre1))
+            work=readings.window_work(recs, cell.arch, cell.config, t0,
+                                      t1, pre0, pre1))
         bounds = devtrace.window_bounds(profile) if profile else None
         if bounds and devtrace.device_planes(profile):
             log("trace lines " + json.dumps(devtrace.describe(profile)))

@@ -7,20 +7,22 @@ import pytest
 
 from bench import model as bm
 from bench import reference as ref
-from bench.tests.tiny import tiny_config
+from bench.tests.tiny import tiny_arch, tiny_config
+
+arch = tiny_arch()
 
 
 @pytest.fixture(scope="module")
 def setup():
     from repro.models.transformer import Model
     cfg = tiny_config()
-    w32 = bm.make_weights(cfg, 2**35 + 3, dtype=jnp.float32)
-    return cfg, w32, Model(bm.arch_config(cfg, "tiny"))
+    w32 = bm.make_weights(arch, cfg, 2**35 + 3, dtype=jnp.float32)
+    return cfg, w32, Model(arch.arch_config(cfg, "tiny"))
 
 
 def ref_logits(w, cfg, toks):
     out = np.zeros((len(toks), cfg["vocab_size"]), np.float32)
-    for sl, lg in ref.logits_at(w, cfg, toks, np.arange(len(toks))):
+    for sl, lg in arch.logits_at(w, cfg, toks, np.arange(len(toks))):
         out[sl] = np.asarray(lg)
     return out
 
@@ -30,9 +32,9 @@ def test_reference_matches_model_forward(setup, tied):
     cfg, _, _ = setup
     from repro.models.transformer import Model
     cfg = dict(cfg, tie_word_embeddings=tied)
-    w = bm.make_weights(cfg, 11, dtype=jnp.float32)
+    w = bm.make_weights(arch, cfg, 11, dtype=jnp.float32)
     assert ("w_out" in w) != tied
-    m = Model(bm.arch_config(cfg, "tiny"))
+    m = Model(arch.arch_config(cfg, "tiny"))
     toks = np.random.default_rng(0).integers(0, cfg["vocab_size"], 70)
     toks = toks.astype(np.int32)
     with jax.default_matmul_precision("highest"):
@@ -53,10 +55,10 @@ def test_gaps_are_zero_for_the_reference_argmax(setup):
         lg = ref_logits(w, cfg, np.asarray(seq, np.int32))
         served.append(int(lg[-1].argmax()))
         seq.append(served[-1])
-    g = ref.gaps(w, cfg, prompt, served)["served"]
+    g = ref.gaps(arch, w, cfg, prompt, served)["served"]
     assert g.shape == (6,) and np.all(g == 0.0)
     wrong = [(t + 1) % cfg["vocab_size"] for t in served]
-    assert ref.gaps(w, cfg, prompt, wrong)["served"].min() > 0.0
+    assert ref.gaps(arch, w, cfg, prompt, wrong)["served"].min() > 0.0
 
 
 def test_fp8_control_reads_far_above_bfloat16(setup):
@@ -76,11 +78,11 @@ def test_fp8_control_reads_far_above_bfloat16(setup):
         # bfloat16 and fp8 argmaxes on the same sequence
         seq_served = cont
         ctl = np.zeros(100, np.int32)
-        for sl, lgc in ref.logits_at(w, cfg, toks[:199], np.arange(99, 199),
-                                     precision="fp8"):
+        for sl, lgc in arch.logits_at(w, cfg, toks[:199],
+                                      np.arange(99, 199), precision="fp8"):
             ctl[sl] = np.asarray(jnp.argmax(lgc, -1))
-        g = ref.gaps(w, cfg, prompt, np.concatenate([seq_served[:1],
-                                                     seq_served[1:]]),
+        g = ref.gaps(arch, w, cfg, prompt,
+                     np.concatenate([seq_served[:1], seq_served[1:]]),
                      {"bf16": served, "control": ctl})
         bf_gap = max(bf_gap, float(g["bf16"].max()))
         ctl_gap = max(ctl_gap, float(g["control"].max()))

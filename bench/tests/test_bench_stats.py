@@ -53,29 +53,30 @@ def test_end_to_end_rates_over_the_window():
 
 def test_window_work_counts_prompts_and_decode_tokens():
     from bench import work
-    from bench.tests.tiny import tiny_config
-    cfg = tiny_config()
+    from bench.tests.tiny import tiny_arch, tiny_config
+    cfg, arch = tiny_config(), tiny_arch()
     r = rec(0, 0.0, [1.0, 2.0, 3.0])
-    w = readings.window_work([r], cfg, 0.0, 10.0, {}, {0: 10})
-    want = work.prefill_flops(cfg, 10) + work.decode_flops(cfg, 11) \
-        + work.decode_flops(cfg, 12)
+    w = readings.window_work([r], arch, cfg, 0.0, 10.0, {}, {0: 10})
+    want = work.prefill_flops(arch, cfg, 10) \
+        + work.decode_flops(arch, cfg, 11) + work.decode_flops(arch, cfg, 12)
     assert w["flops"] == pytest.approx(want)
     assert w["decode_tokens"] == 2 and w["prompt_tokens"] == 10
 
 
 def test_window_work_charges_only_chunks_dispatched_in_the_window():
     from bench import work
-    from bench.tests.tiny import tiny_config
-    cfg = tiny_config()
+    from bench.tests.tiny import tiny_arch, tiny_config
+    cfg, arch = tiny_config(), tiny_arch()
     # 4 prompt positions ran before the window, 6 in it; the first token
     # came in the window and is no decode token
     r = rec(0, 0.0, [5.0])
-    w = readings.window_work([r], cfg, 1.0, 10.0, {0: 4}, {0: 10})
-    assert w["flops"] == pytest.approx(work.prefill_flops(cfg, 6, start=4))
+    w = readings.window_work([r], arch, cfg, 1.0, 10.0, {0: 4}, {0: 10})
+    assert w["flops"] == pytest.approx(
+        work.prefill_flops(arch, cfg, 6, start=4))
     assert w["prompt_tokens"] == 6 and w["decode_tokens"] == 0
     # a prompt whose chunks all ran before the window costs it nothing
-    w = readings.window_work([r], cfg, 1.0, 10.0, {0: 10}, {0: 10})
+    w = readings.window_work([r], arch, cfg, 1.0, 10.0, {0: 10}, {0: 10})
     assert w["flops"] == 0.0
     # a chunk that does not end the prompt needs no output head
-    assert work.prefill_flops(cfg, 4, head=False) == pytest.approx(
-        work.prefill_flops(cfg, 4) - work.head_flops(cfg))
+    assert work.prefill_flops(arch, cfg, 4, head=False) == pytest.approx(
+        work.prefill_flops(arch, cfg, 4) - arch.head_flops(cfg))

@@ -2,10 +2,11 @@
 from __future__ import annotations
 
 import copy
+from functools import cache
 from pathlib import Path
 
 from bench import loadgen
-from bench.model import load_config
+from bench.model import load_arch, load_config
 
 ROOT = Path(__file__).resolve().parents[2]
 
@@ -21,6 +22,13 @@ def tiny_config(layers: int = 2) -> dict:
     # (bench/limits.py); the broken paths of test_bench_faults read above 1
     cfg["check"]["max_logit_gap"] = 0.05
     return cfg
+
+
+@cache
+def tiny_arch():
+    """The architecture module ``tiny_config`` names (``qwen3``), loaded
+    once, so the reference's programs compile once per test process."""
+    return load_arch(ROOT, tiny_config(), "tiny")
 
 
 def small_sample(mp):
@@ -46,5 +54,6 @@ def tiny_cell():
         "compiles_in_window", "duet_share", "prefix_hit_rate", "step_mfu",
         "device_idle_share")]
     return Cell(name="tiny", chips=1, config=tiny_config(),
-                config_name="tiny", mix=tiny_mix(), end_to_end=e2e,
-                per_layer=pl, metrics_dir=ROOT / "bench" / "metrics")
+                arch=tiny_arch(), config_name="tiny", mix=tiny_mix(),
+                end_to_end=e2e, per_layer=pl,
+                metrics_dir=ROOT / "bench" / "metrics")
